@@ -30,6 +30,7 @@ from pathlib import Path
 from typing import List, Optional
 
 import jax
+import zstandard
 
 from repro.checkpoint import chunkstore
 from repro.checkpoint import serialization as ser
@@ -240,7 +241,11 @@ class CheckpointManager:
         flip is first caught by the digest check DURING the restore read;
         when auto-picking, such a dir is skipped and the next older valid
         checkpoint is served (the pre-chunk-store 'corrupt ones skipped'
-        guarantee).  An explicit `ckpt_dir` still raises."""
+        guarantee).  An explicit `ckpt_dir` still raises.  Only reader-side
+        corruption is skipped: a device or runtime error while placing a
+        restored leaf (``jax.errors.JaxRuntimeError``, out of memory
+        among them) propagates, instead of silently restarting from
+        step 0."""
         if ckpt_dir is not None:
             with _trace.span("ckptmgr.restore", cat="ckpt",
                              args={"dir": ckpt_dir.name}):
@@ -263,7 +268,7 @@ class CheckpointManager:
                                               store=self.store,
                                               workers=self.writer_threads,
                                               stats=self.stats)
-            except (OSError, zlib.error, RuntimeError, ValueError):
+            except (OSError, zlib.error, zstandard.ZstdError, ValueError):
                 # payload-level corruption the fast validate can't see
                 # (digest mismatch, truncated codec stream): skip this dir
                 self._known_valid.discard(d.name)

@@ -44,17 +44,11 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+import zstandard
 
 from repro.checkpoint import chunkstore
 from repro.checkpoint.chunkstore import (ChunkReader, ChunkStoreBackend,
                                          content_digest)
-
-try:                                    # zstandard is optional: fall back to
-    import zstandard                    # zlib so the core C/R path has no
-    HAVE_ZSTD = True                    # dependency beyond the stdlib
-except ImportError:                     # pragma: no cover - env dependent
-    zstandard = None
-    HAVE_ZSTD = False
 
 
 class _ZlibCompressor:
@@ -70,16 +64,13 @@ class _ZlibDecompressor:
 def _codec_pair(codec: str):
     """(compressor, decompressor) for a manifest codec name."""
     if codec == "zstd":
-        if not HAVE_ZSTD:
-            raise RuntimeError(
-                "checkpoint written with zstd but zstandard is not installed")
         return zstandard.ZstdCompressor(level=3), zstandard.ZstdDecompressor()
     if codec == "zlib":
         return _ZlibCompressor(), _ZlibDecompressor()
     raise ValueError(f"unknown checkpoint codec {codec!r}")
 
 
-DEFAULT_CODEC = "zstd" if HAVE_ZSTD else "zlib"
+DEFAULT_CODEC = "zstd"
 
 #: default writer-pool width; compression releases the GIL so threads give
 #: real parallelism.  Kept modest: past the storage bandwidth more threads

@@ -22,7 +22,7 @@ import jax
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.distributed.sharding import ShardingRules, make_variant
-from repro.launch.mesh import _make
+from repro.launch.mesh import make_mesh
 
 
 def choose_mesh(n_devices: Optional[int] = None,
@@ -32,7 +32,7 @@ def choose_mesh(n_devices: Optional[int] = None,
     model = model_parallel
     while n % model:
         model -= 1
-    return _make((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def elastic_restore(mgr: CheckpointManager, template, mesh,

@@ -1,9 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute with interpret=True (the kernel
-body runs in Python for correctness validation); on TPU they compile to
-Mosaic.  ``flash_attention`` carries a custom_vjp whose backward is the
-pure-jnp reference gradient (recompute-based) — the forward kernel is the
+On the CPU platform kernels execute with interpret=True (the kernel body
+runs in Python for correctness validation); on TPU they compile to
+Mosaic; any other backend is refused rather than silently interpreted.
+``flash_attention`` carries a custom_vjp whose backward is the pure-jnp
+reference gradient (recompute-based) — the forward kernel is the
 serving/prefill fast path; a fused backward kernel is listed as future
 work in DESIGN.md §6."""
 from __future__ import annotations
@@ -20,7 +21,12 @@ from repro.kernels import ref as _ref
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise NotImplementedError(
+            f"Pallas kernels compile for TPU and interpret on CPU; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 # ---------------------------------------------------------------- attention

@@ -3,21 +3,13 @@ this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
-def _make(shape, axes):
-    return compat_make_mesh(shape, axes)
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist in newer releases; older ones
-    default every axis to Auto anyway, which is what we want."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto (the sharding rules place
+    arrays; the compiler propagates the rest)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -25,11 +17,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2x16x16 = 512 chips (pod, data, model); "pod" crosses DCN."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(n: int | None = None, model: int = 1):
     """Mesh over locally visible devices (smoke tests, CPU examples)."""
     n = n or len(jax.devices())
     assert n % model == 0, (n, model)
-    return _make((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

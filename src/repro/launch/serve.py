@@ -15,6 +15,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import ARCHS, get_arch, reduce_for_smoke
 from repro.distributed.sharding import make_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.params import init_params
 from repro.models.registry import get_api
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--model-parallel", type=int, default=1)
     ap.add_argument("--snapshot-dir", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

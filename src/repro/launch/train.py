@@ -15,6 +15,7 @@ import json
 
 from repro.configs import ARCHS, get_arch, reduce_for_smoke
 from repro.distributed.sharding import make_variant
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.train.loop import train
 from repro.train.step import default_accum
@@ -39,6 +40,7 @@ def main() -> None:
                     help="smoke-scale config (CPU demo)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

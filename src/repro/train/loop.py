@@ -7,8 +7,12 @@ RUN -> (every ckpt_every steps) QUIESCE/DRAIN -> SNAPSHOT -> RESUME
   snapshot = TrainState pytree + pipeline cursor + rng; nothing else exists
              to save — the functional step makes the proxy boundary
              structural (DESIGN.md §2)
-  restore  = newest valid checkpoint, auto-resumed, resharded onto the
-             current mesh (elastic).
+  restore  = newest valid checkpoint, auto-resumed, placed with the
+             step's state shardings on the current mesh.
+
+Fresh and restored state, and every batch, are laid out with the step's
+own shardings, which are also the jit's in/out shardings: on a (4, 1)
+mesh the state lands on all four devices, not on the default one.
 """
 from __future__ import annotations
 
@@ -19,13 +23,14 @@ from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.base import ArchConfig
 from repro.data.pipeline import TokenPipeline
-from repro.distributed.sharding import ShardingRules
+from repro.distributed.sharding import ShardingRules, resolve_spec
 from repro.models.layers import Policy
-from repro.train.state import make_train_state, state_shardings
+from repro.train.state import make_train_state
 from repro.train.step import make_train_step
 
 
@@ -36,6 +41,9 @@ class TrainResult:
     resumed_from: Optional[int] = None
     ckpt_stats: dict = field(default_factory=dict)
     wall_s: float = 0.0
+    #: the final TrainState, laid out on the mesh (callers inspect its
+    #: shardings; drop it to free the device memory)
+    state: Any = None
 
 
 def train(cfg: ArchConfig, mesh, rules: ShardingRules, *,
@@ -61,7 +69,12 @@ def train(cfg: ArchConfig, mesh, rules: ShardingRules, *,
         cfg, mesh, rules, accum_steps=accum_steps, base_lr=base_lr,
         warmup=warmup, policy=policy, max_seq=seq_len, total_steps=n_steps,
         remat=remat)
-    jit_step = jax.jit(step_fn, donate_argnums=(0,))
+    tok = NamedSharding(mesh, resolve_spec(("batch", "seq"),
+                                           (global_batch, seq_len), mesh, rules))
+    b_shard = {"tokens": tok, "targets": tok}
+    jit_step = jax.jit(step_fn, in_shardings=(st_shard, b_shard),
+                       out_shardings=(st_shard, None), donate_argnums=(0,))
+    rep = NamedSharding(mesh, P())
 
     result = TrainResult()
     mgr = None
@@ -73,21 +86,23 @@ def train(cfg: ArchConfig, mesh, rules: ShardingRules, *,
             lambda: make_train_state(cfg, jax.random.PRNGKey(seed), seq_len))
         template = {"train": template,
                     "data": {"seed": np.int64(0), "cursor": np.int64(0)}}
-        restored, meta = mgr.restore(template, None)
+        restored, meta = mgr.restore(
+            template, {"train": st_shard, "data": {"seed": rep, "cursor": rep}})
         if restored is not None:
-            state = jax.tree.map(jax.numpy.asarray, restored["train"])
+            state = restored["train"]
             pipe = TokenPipeline(cfg.vocab_size, global_batch, seq_len,
                                  seed=int(restored["data"]["seed"]))
             pipe.cursor = int(restored["data"]["cursor"])
             result.resumed_from = int(meta.get("step", -1))
     if state is None:
-        state = make_train_state(cfg, jax.random.PRNGKey(seed), seq_len)
+        state = jax.device_put(
+            make_train_state(cfg, jax.random.PRNGKey(seed), seq_len), st_shard)
         pipe = TokenPipeline(cfg.vocab_size, global_batch, seq_len, seed=seed)
 
     start_step = int(state["step"])
     for step in range(start_step, n_steps):
         batch = pipe.next_batch()
-        batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
+        batch = {k: jax.device_put(v, b_shard[k]) for k, v in batch.items()}
         state, metrics = jit_step(state, batch)
         if step % log_every == 0 or step == n_steps - 1:
             loss = float(metrics["loss"])
@@ -108,5 +123,6 @@ def train(cfg: ArchConfig, mesh, rules: ShardingRules, *,
     if mgr is not None:
         mgr.wait()
         result.ckpt_stats = dict(mgr.stats)
+    result.state = state
     result.wall_s = time.time() - t_start
     return result

@@ -40,8 +40,6 @@ def test_save_restore_roundtrip_exact(tmp_path):
 def test_shard_codec_roundtrip(tmp_path, codec):
     """Both shard codecs round-trip bitwise; the manifest records which one
     wrote the checkpoint so the reader never has to guess."""
-    if codec == "zstd" and not ser.HAVE_ZSTD:
-        pytest.skip("zstandard not installed")
     st = _state()
     ser.save_shards(tmp_path, st, codec=codec)
     man = ser.load_manifest(tmp_path)
@@ -104,6 +102,39 @@ def test_restore_falls_back_past_size_preserving_bitflip(tmp_path):
     assert ser.validate(tmp_path / "step_0000000002")   # fast path fooled
     out, meta = mgr.restore(jax.eval_shape(lambda: _state()))
     assert meta["step"] == 1                            # ...restore wasn't
+    for a, b in zip(jax.tree.leaves(_state(1)), jax.tree.leaves(out)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_device_error_during_restore_propagates(tmp_path, monkeypatch):
+    """Only reader-side corruption skips a checkpoint.  A device error
+    while placing a restored leaf (out of memory, a lost chip) must reach
+    the caller: swallowing it would skip every checkpoint and silently
+    restart training from step 0.  A digest mismatch is still skipped in
+    favour of the next older checkpoint."""
+    from repro.checkpoint import resharding
+    mgr = CheckpointManager(tmp_path, keep=5)
+    mgr.save(1, _state(1)); mgr.wait()
+    mgr.save(2, _state(2)); mgr.wait()
+    tpl = jax.eval_shape(lambda: _state())
+
+    def oom(*a, **k):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    with monkeypatch.context() as mp:
+        mp.setattr(resharding.jax, "device_put", oom)
+        with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXH"):
+            mgr.restore(tpl)
+    assert mgr.stats["restores"] == 0
+
+    only2 = _chunks_of(tmp_path / "step_0000000002") \
+        - _chunks_of(tmp_path / "step_0000000001")
+    victim = tmp_path / "chunks" / sorted(only2)[0]
+    blob = bytearray(victim.read_bytes())
+    blob[len(blob) // 2] ^= 0xFF
+    victim.write_bytes(bytes(blob))
+    out, meta = mgr.restore(tpl)
+    assert meta["step"] == 1
     for a, b in zip(jax.tree.leaves(_state(1)), jax.tree.leaves(out)):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 

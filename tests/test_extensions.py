@@ -5,11 +5,14 @@ import os
 import subprocess
 import sys
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from repro.core import MPIJob
+
+ROOT = Path(__file__).resolve().parents[1]
 
 
 def run_app(n, step_fn, init_fn=lambda mpi: {}, steps=1, **kw):
@@ -139,9 +142,11 @@ def test_launch_train_cli(tmp_path):
     r = subprocess.run(
         [sys.executable, "-m", "repro.launch.train", "--arch", "smollm-135m",
          "--reduced", "--steps", "3", "--batch", "2", "--seq", "32",
-         "--ckpt-dir", str(tmp_path)],
+         "--ckpt-dir", str(tmp_path / "ckpt")],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd="/root/repo")
+        env={**os.environ, "PYTHONPATH": "src",
+             "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache")},
+        cwd=ROOT)
     assert r.returncode == 0, r.stderr[-2000:]
     last = json.loads(r.stdout.strip().splitlines()[-1])
     assert last["steps_run"] == 3 and np.isfinite(last["final_loss"])
@@ -154,7 +159,9 @@ def test_launch_serve_cli(tmp_path):
          "--reduced", "--batch", "2", "--prompt-len", "8",
          "--new-tokens", "8"],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd="/root/repo")
+        env={**os.environ, "PYTHONPATH": "src",
+             "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax_cache")},
+        cwd=ROOT)
     assert r.returncode == 0, r.stderr[-2000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["tok_per_s"] > 0

@@ -5,7 +5,7 @@ Covers the three promises the design makes:
   * rounds are EXACT — a round manifest lists every leaf, ships exactly
     the leaves whose content changed since the previous round, and
     references the rest (property-tested: a seeded randomized sweep that
-    always runs, plus a hypothesis variant when it is installed);
+    always runs, plus a hypothesis variant);
   * migration is INVISIBLE to the application — a world that live-migrated
     a rank mid-run finishes bit-identical to an unmigrated control, on
     every fabric (shm / tcp / proc);
@@ -22,6 +22,8 @@ from pathlib import Path
 
 import numpy as np
 import pytest
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.checkpoint.chunkstore import ChunkStore, content_digest
 from repro.core import migrate as migration
@@ -29,7 +31,6 @@ from repro.core.ckpt_protocol import checkpoint_valid, load_manifest
 from repro.core.coordinator import Membership
 from repro.core.runtime import MPIJob
 
-from _hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
 from conftest import exact_transports
 
 N = 2
@@ -132,7 +133,6 @@ def test_stream_round_ships_exactly_dirty_leaves(tmp_path, rng):
         prev, prev_entry = digests, entry
 
 
-@pytest.mark.skipif(not HAVE_HYPOTHESIS, reason="hypothesis not installed")
 @settings(max_examples=25, deadline=None)
 @given(st.lists(st.dictionaries(
     st.sampled_from(["a", "b", "c", "d"]), st.binary(max_size=64),

@@ -23,8 +23,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.manager import CheckpointManager
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh({mesh_shape}, {mesh_axes})
+from repro.launch.mesh import make_mesh
+mesh = make_mesh({mesh_shape}, {mesh_axes})
 w = jnp.arange(16 * 8, dtype=jnp.float32).reshape(16, 8)
 w = jax.device_put(w, NamedSharding(mesh, P({spec})))
 b = jnp.arange(8, dtype=jnp.bfloat16)
@@ -43,8 +43,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint.manager import CheckpointManager
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh({mesh_shape}, {mesh_axes})
+from repro.launch.mesh import make_mesh
+mesh = make_mesh({mesh_shape}, {mesh_axes})
 tpl = {{"w": jax.ShapeDtypeStruct((16, 8), jnp.float32),
        "b": jax.ShapeDtypeStruct((8,), jnp.bfloat16)}}
 sh = {{"w": NamedSharding(mesh, P({spec})), "b": NamedSharding(mesh, P())}}

@@ -3,7 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.optim.adamw import (AdamWCfg, adamw_update, cosine_schedule,
                                global_norm, init_opt_state)
@@ -70,8 +71,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, json
 from jax.sharding import PartitionSpec as P
 from repro.distributed.sharding import make_variant, resolve_spec
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 4), ("data", "model"))
 r = make_variant("baseline")
 checks = []
 # divisible head dim shards on model
