@@ -55,12 +55,13 @@ def main() -> None:
     except RuntimeError as e:
         print(f"CRASHED (as requested): {e} — rerun to auto-resume")
         raise SystemExit(0)
-    tok_s = args.steps * args.batch * args.seq / res.wall_s
+    wall = time.time() - t0
+    tok_s = args.steps * args.batch * args.seq / wall
     print(f"losses: {['%.4f' % l for l in res.losses[:3]]} ... "
           f"{['%.4f' % l for l in res.losses[-3:]]}")
     if res.resumed_from is not None:
         print(f"auto-resumed from checkpoint at step {res.resumed_from}")
-    print(f"done in {time.time()-t0:.1f}s ({tok_s:.0f} tok/s); "
+    print(f"done in {wall:.1f}s ({tok_s:.0f} tok/s); "
           f"ckpt stats: {res.ckpt_stats}")
 
 

@@ -2,9 +2,11 @@
 loop (DESIGN.md §2 mapping, §9 storage layout).
 
   drain    = jax.block_until_ready on the state (all dispatched steps and
-             async transfers complete) + wait for the previous async write
+             async transfers complete; ``drain_device_s``), then wait for
+             the previous async write (``drain_write_wait_s``)
   snapshot = device->host copy of the pure pytree (replicated shards
-             deduped BEFORE the copy), handed to a background writer
+             deduped BEFORE the copy; ``snapshot_transfer_s`` and
+             ``snapshot_copy_s`` split it), handed to a background writer
              (the storage 'proxy'; training never blocks on the filesystem)
   commit   = content-addressed chunks + v3 manifest, atomic rename;
              unchanged chunks are REFERENCED, not rewritten (incremental)
@@ -77,8 +79,12 @@ class CheckpointManager:
         #: under the group lock and ``snapshot()`` is one consistent view
         self.stats = _metrics.MetricGroup(
             "ckpt_manager",
-            {"saves": 0, "drain_s": 0.0, "snapshot_s": 0.0,
-             "write_s": 0.0, "gc_removed": 0,
+            {"saves": 0, "drain_device_s": 0.0, "drain_write_wait_s": 0.0,
+             "snapshot_s": 0.0, "write_s": 0.0, "gc_removed": 0,
+             # the snapshot's parts: device->host transfer, host copy,
+             # bytes kept after dedup
+             "snapshot_transfer_s": 0.0, "snapshot_copy_s": 0.0,
+             "snapshot_bytes": 0,
              # pipeline stage timings (summed across pool threads)
              "hash_s": 0.0, "compress_s": 0.0, "io_s": 0.0,
              # incremental accounting, cumulative and per-save
@@ -104,15 +110,24 @@ class CheckpointManager:
                                  generation=self.generation,
                                  args={"step": step})
         t0 = time.time()
-        with _trace.span("ckptmgr.drain", parent=save_span, cat="ckpt"):
+        with _trace.span("ckptmgr.drain_device", parent=save_span,
+                         cat="ckpt"):
             jax.block_until_ready(state)      # drain dispatched computation
+        t1 = time.time()
+        with _trace.span("ckptmgr.write_wait", parent=save_span, cat="ckpt"):
             self.wait()                       # drain the previous async write
-        self.stats["drain_s"] += time.time() - t0
+        self.stats["drain_device_s"] += t1 - t0
+        self.stats["drain_write_wait_s"] += time.time() - t1
 
         t0 = time.time()
-        with _trace.span("ckptmgr.snapshot", parent=save_span, cat="ckpt"):
+        with _trace.span("ckptmgr.snapshot", parent=save_span,
+                         cat="ckpt") as snap_span:
             host_state = ser.snapshot_to_host(state)  # sync: donation-safe
+            parts = ser.snapshot_parts(host_state)
+            snap_span.end(**parts)
         self.stats["snapshot_s"] += time.time() - t0
+        for k, v in parts.items():
+            self.stats[k] += v
 
         ckpt_dir = self.root / f"step_{step:010d}"
         meta = dict(meta or {}, step=step, time=time.time())

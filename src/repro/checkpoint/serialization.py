@@ -191,12 +191,20 @@ class HostArray:
 
     Replicated shards are deduplicated by index window BEFORE the
     device->host copy: a leaf replicated over N devices costs one transfer
-    and one host buffer, not N transfers discarded at write time."""
+    and one host buffer, not N transfers discarded at write time.
+
+    Each array keeps the parts of its own snapshot: ``transfer_s``
+    (inside ``np.asarray``: the device->host transfer), ``copy_s``
+    (inside ``.copy()``: the host-side copy) and ``nbytes`` (the bytes
+    kept, after dedup); ``snapshot_parts`` sums them over a tree."""
 
     def __init__(self, x):
         self.shape = tuple(x.shape)
         self.dtype = str(x.dtype)
         self.shards = []
+        self.transfer_s = 0.0
+        self.copy_s = 0.0
+        self.nbytes = 0
         seen = set()
         for sh in x.addressable_shards:
             idx = [[s.start or 0,
@@ -206,8 +214,15 @@ class HostArray:
             if key in seen:
                 continue
             seen.add(key)
-            self.shards.append((idx, np.asarray(sh.data).copy(),
-                                int(sh.device.id)))
+            t0 = time.perf_counter()
+            fetched = np.asarray(sh.data)
+            t1 = time.perf_counter()
+            data = fetched.copy()
+            t2 = time.perf_counter()
+            self.transfer_s += t1 - t0
+            self.copy_s += t2 - t1
+            self.nbytes += data.nbytes
+            self.shards.append((idx, data, int(sh.device.id)))
 
 
 def snapshot_to_host(tree):
@@ -217,6 +232,16 @@ def snapshot_to_host(tree):
             return HostArray(x)
         return np.asarray(x).copy()
     return jax.tree.map(conv, tree)
+
+
+def snapshot_parts(host_tree) -> Dict[str, float]:
+    """The snapshot's parts summed over its HostArray leaves:
+    ``snapshot_transfer_s``, ``snapshot_copy_s`` and ``snapshot_bytes``."""
+    arrays = [x for x in jax.tree.leaves(host_tree)
+              if isinstance(x, HostArray)]
+    return {"snapshot_transfer_s": sum(a.transfer_s for a in arrays),
+            "snapshot_copy_s": sum(a.copy_s for a in arrays),
+            "snapshot_bytes": sum(a.nbytes for a in arrays)}
 
 
 def _leaf_paths(tree) -> List[Tuple[str, Any]]:

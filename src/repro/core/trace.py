@@ -12,13 +12,24 @@ into a single Chrome-trace/Perfetto JSON timeline:
 
     python -m repro.core.trace merge $REPRO_TRACE_DIR -o timeline.json
 
+A context-manager span (``with span(...)``) is also mirrored into the JAX
+profiler: while it is open it holds a ``jax.profiler.TraceAnnotation`` of
+the same name, so a ``jax.profiler`` trace of the process shows it as a
+host event on the profiler's own clock, next to the device's operations.
+Detached ``begin()``/``end()`` handles stay recorder-only: they may open
+and close on different threads, and an annotation may not.  This module
+never imports jax: forked rank children must not load XLA.  The
+annotation class is looked up only once the process has imported jax
+itself, and a forked child never mirrors (a profiler session does not
+survive ``fork()``).
+
 Design constraints, in order:
 
   * ``REPRO_TRACE=0`` compiles to no-ops: every emit helper checks one
-    module-level flag first and returns a shared null object, so the
-    disabled cost is a global load + branch.  The enabled cost is
-    CI-gated (<= 5% on the proxied allreduce loop,
-    BENCH_observability.json).
+    module-level flag first and returns a shared null object, which
+    opens no annotation either, so the disabled cost is a global load +
+    branch.  The enabled cost is CI-gated (<= 5% on the proxied
+    allreduce loop, BENCH_observability.json).
   * Causality beats precision: span ids parent child work under the
     coordinating operation, propagated across the proc-world socket
     boundary by piggybacking ``(trace_id, span_id)`` on the coord-state
@@ -31,7 +42,8 @@ Design constraints, in order:
   * The ring is bounded (``REPRO_TRACE_RING`` events, oldest evicted):
     a week-long world dumps the same size file as a ten-second test.
   * fork() inherits the parent's ring; an ``os.register_at_fork`` hook
-    clears it in the child so rank dumps contain only their own events.
+    clears it in the child so rank dumps contain only their own events,
+    and turns the profiler mirror off there.
 """
 from __future__ import annotations
 
@@ -39,6 +51,7 @@ import itertools
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -156,10 +169,31 @@ class FlightRecorder:
 
 _RECORDER = FlightRecorder()
 
-# fork() copies the parent's ring into the child: clear it so a rank
-# child's dump holds only events that happened in that rank's process
+# -- profiler mirror ---------------------------------------------------------
+# jax.profiler.TraceAnnotation, found in sys.modules once the process has
+# imported jax (never imported from here); _MIRROR is off in forked children
+_ANNOTATION = None
+_MIRROR = True
+
+
+def _annotation_class():
+    global _ANNOTATION
+    prof = sys.modules.get("jax.profiler")
+    if prof is not None:
+        _ANNOTATION = getattr(prof, "TraceAnnotation", None)
+    return _ANNOTATION
+
+
+def _after_fork_in_child() -> None:
+    # fork() copies the parent's ring into the child: clear it so a rank
+    # child's dump holds only events that happened in that rank's process
+    global _MIRROR
+    _RECORDER.clear()
+    _MIRROR = False
+
+
 if hasattr(os, "register_at_fork"):          # pragma: no branch
-    os.register_at_fork(after_in_child=_RECORDER.clear)
+    os.register_at_fork(after_in_child=_after_fork_in_child)
 
 
 def recorder() -> FlightRecorder:
@@ -208,13 +242,13 @@ def _resolve_parent(parent) -> Tuple[int, Optional[int]]:
 
 class _Span:
     """An open span.  Context-manager use attaches it to the thread's
-    context stack; ``begin()``/``end()`` handle use (the coordinator's
-    phase spans, which open and close from different callers) does not.
-    ``end`` is idempotent."""
+    context stack and mirrors it into the profiler; ``begin()``/``end()``
+    handle use (the coordinator's phase spans, which open and close from
+    different callers) does neither.  ``end`` is idempotent."""
 
     __slots__ = ("name", "cat", "rank", "generation", "args",
                  "trace_id", "span_id", "parent_id", "t0", "_open",
-                 "_attached")
+                 "_attached", "_ann")
 
     def __init__(self, name: str, parent=None, cat: str = "repro",
                  rank: Optional[int] = None,
@@ -230,6 +264,7 @@ class _Span:
         self.t0 = time.monotonic()
         self._open = True
         self._attached = False
+        self._ann = None
 
     @property
     def ctx(self) -> Ctx:
@@ -238,9 +273,19 @@ class _Span:
     def __enter__(self) -> "_Span":
         _stack().append(self.ctx)
         self._attached = True
+        cls = (_ANNOTATION or _annotation_class()) if _MIRROR else None
+        if cls is not None:
+            self._ann = cls(self.name)
+            self._ann.__enter__()
+            # the recorder's start is the annotation's, to the microsecond
+            self.t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self._attached:
             st = _stack()
             if st and st[-1] == self.ctx:
@@ -248,9 +293,12 @@ class _Span:
             self._attached = False
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
-        self.end()
+        self._close(t1)
 
     def end(self, **extra) -> None:
+        self._close(time.monotonic(), extra)
+
+    def _close(self, t1: float, extra: Optional[dict] = None) -> None:
         if not self._open:
             return
         self._open = False
@@ -258,9 +306,9 @@ class _Span:
             self.args.update(extra)
         _RECORDER.add(SpanEvent(
             name=self.name, trace_id=self.trace_id, span_id=self.span_id,
-            parent_id=self.parent_id, t0=self.t0,
-            dur=time.monotonic() - self.t0, pid=os.getpid(), cat=self.cat,
-            rank=self.rank, generation=self.generation, args=self.args))
+            parent_id=self.parent_id, t0=self.t0, dur=t1 - self.t0,
+            pid=os.getpid(), cat=self.cat, rank=self.rank,
+            generation=self.generation, args=self.args))
 
 
 class _NullSpan:

@@ -267,7 +267,9 @@ COORD_STATS_KEYS = {
     "recovery_wall_s", "recovered_ops", "rerun_ops", "recovery_cancelled"}
 
 CKPT_MANAGER_STATS_KEYS = {
-    "saves", "drain_s", "snapshot_s", "write_s", "gc_removed", "hash_s",
+    "saves", "drain_device_s", "drain_write_wait_s", "snapshot_s",
+    "snapshot_transfer_s", "snapshot_copy_s", "snapshot_bytes", "write_s",
+    "gc_removed", "hash_s",
     "compress_s", "io_s", "bytes_written", "bytes_referenced",
     "last_bytes_written", "last_bytes_referenced", "chunks_gc_removed",
     "last_bytes_uploaded", "last_bytes_referenced_remote", "restores",
