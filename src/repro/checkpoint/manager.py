@@ -4,10 +4,15 @@ loop (DESIGN.md §2 mapping, §9 storage layout).
   drain    = jax.block_until_ready on the state (all dispatched steps and
              async transfers complete; ``drain_device_s``), then wait for
              the previous async write (``drain_write_wait_s``)
-  snapshot = device->host copy of the pure pytree (replicated shards
-             deduped BEFORE the copy; ``snapshot_transfer_s`` and
-             ``snapshot_copy_s`` split it), handed to a background writer
-             (the storage 'proxy'; training never blocks on the filesystem)
+  snapshot = device copy of the pure pytree (``snapshot_s``, the time the
+             training thread spends on it); the background writer (the
+             storage 'proxy'; training never blocks on the filesystem)
+             fetches that copy and copies it on the host (replicated
+             shards deduped BEFORE the fetch; ``snapshot_transfer_s`` and
+             ``snapshot_copy_s``), then drops it.  The copy costs one more
+             state per device while a write runs; where the device lacks
+             room for it, the host snapshot is taken on the training
+             thread instead (``snapshot_host_fallbacks``)
   commit   = content-addressed chunks + v3 manifest, atomic rename;
              unchanged chunks are REFERENCED, not rewritten (incremental)
   restore  = newest VALID checkpoint (corrupt/partial ones skipped,
@@ -23,6 +28,7 @@ references; the last remaining valid checkpoint is never removed.
 """
 from __future__ import annotations
 
+import math
 import re
 import shutil
 import threading
@@ -41,6 +47,42 @@ from repro.core import metrics as _metrics
 from repro.core import trace as _trace
 
 _STEP_RE = re.compile(r"^step_(\d+)$")
+
+
+def _copy_bytes(tree) -> dict:
+    """{device: bytes} a device copy of the tree's jax.Array leaves takes."""
+    need: dict = {}
+    for x in jax.tree.leaves(tree):
+        if isinstance(x, jax.Array):
+            n = math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+            for d in x.sharding.addressable_devices:
+                need[d] = need.get(d, 0) + n
+    return need
+
+
+def _free_bytes(device) -> Optional[int]:
+    """Bytes the device can still hand out, from its own report: its limit
+    less the live buffers (``bytes_in_use``: after the drain, the state
+    and the batch; the peak would charge the previous save's copy again)
+    and less what loaded programs hold reserved for their temporaries
+    (``bytes_reserved``: on a TPU, the compiled step's scratch, which
+    ``bytes_in_use`` leaves out).  None where the backend reports nothing
+    (the CPU)."""
+    st = device.memory_stats()
+    if not st or "bytes_limit" not in st:
+        return None
+    return (st["bytes_limit"] - st["bytes_in_use"]
+            - st.get("bytes_reserved", 0))
+
+
+def copy_fits(tree) -> bool:
+    """Whether every device holding the tree has room for its copy.  True
+    where a device reports no memory stats."""
+    for d, n in _copy_bytes(tree).items():
+        free = _free_bytes(d)
+        if free is not None and free < n:
+            return False
+    return True
 
 
 class CheckpointManager:
@@ -85,6 +127,10 @@ class CheckpointManager:
              # bytes kept after dedup
              "snapshot_transfer_s": 0.0, "snapshot_copy_s": 0.0,
              "snapshot_bytes": 0,
+             # which snapshot each save took: a device copy fetched by the
+             # writer, or (no room on the device) the host snapshot on the
+             # training thread
+             "snapshot_device_copies": 0, "snapshot_host_fallbacks": 0,
              # pipeline stage timings (summed across pool threads)
              "hash_s": 0.0, "compress_s": 0.0, "io_s": 0.0,
              # incremental accounting, cumulative and per-save
@@ -102,10 +148,15 @@ class CheckpointManager:
 
     # ------------------------------------------------------------------ save
     def save(self, step: int, state, meta: Optional[dict] = None) -> Path:
-        """Drain -> host snapshot -> async commit.  Returns the ckpt dir.
+        """Drain -> device copy -> async commit.  Returns the ckpt dir.
         The manifest meta records the SOURCE world (device count + mesh
         when the caller provides one) and the membership generation, so a
-        later elastic restore can report the topology change."""
+        later elastic restore can report the topology change.
+
+        The snapshot is a device copy of every jax.Array leaf, which the
+        writer fetches to the host; where a device lacks room for it
+        (``copy_fits``), the host snapshot is taken here instead.  Either
+        way no later donation of `state` reaches the checkpoint."""
         save_span = _trace.begin("ckptmgr.save", cat="ckpt",
                                  generation=self.generation,
                                  args={"step": step})
@@ -120,14 +171,16 @@ class CheckpointManager:
         self.stats["drain_write_wait_s"] += time.time() - t1
 
         t0 = time.time()
-        with _trace.span("ckptmgr.snapshot", parent=save_span,
-                         cat="ckpt") as snap_span:
-            host_state = ser.snapshot_to_host(state)  # sync: donation-safe
-            parts = ser.snapshot_parts(host_state)
-            snap_span.end(**parts)
+        device_state = host_state = None
+        if copy_fits(state):
+            with _trace.span("ckptmgr.device_copy", parent=save_span,
+                             cat="ckpt"):
+                device_state = ser.device_copy(state)
+            self.stats["snapshot_device_copies"] += 1
+        else:
+            host_state = self._snapshot(state, save_span)
+            self.stats["snapshot_host_fallbacks"] += 1
         self.stats["snapshot_s"] += time.time() - t0
-        for k, v in parts.items():
-            self.stats[k] += v
 
         ckpt_dir = self.root / f"step_{step:010d}"
         meta = dict(meta or {}, step=step, time=time.time())
@@ -135,12 +188,19 @@ class CheckpointManager:
         meta.setdefault("generation", self.generation)
 
         def _write():
+            nonlocal device_state, host_state
             t1 = time.time()
-            w0 = self.store.stats["bytes_written"]
-            r0 = self.store.stats["bytes_referenced"]
-            u0 = self.store.stats.get("bytes_uploaded", 0)
-            rr0 = self.store.stats.get("bytes_referenced_remote", 0)
             try:
+                if host_state is None:
+                    # fetched, the device copy is dropped: its memory is
+                    # free while the write runs
+                    host_state = self._snapshot(device_state, save_span)
+                    device_state = None
+                t1 = time.time()
+                w0 = self.store.stats["bytes_written"]
+                r0 = self.store.stats["bytes_referenced"]
+                u0 = self.store.stats.get("bytes_uploaded", 0)
+                rr0 = self.store.stats.get("bytes_referenced_remote", 0)
                 # context-manager span: runs on the ckpt-writer thread, so
                 # the explicit parent handle (not the spawning thread's
                 # stack) links it under the save — and chunk-store RPC
@@ -190,6 +250,18 @@ class CheckpointManager:
             _write()
             self._raise_pending()
         return ckpt_dir
+
+    def _snapshot(self, tree, save_span):
+        """Host snapshot of `tree` (``ser.snapshot_to_host``, looked up at
+        call time), its parts added to the stats."""
+        with _trace.span("ckptmgr.snapshot", parent=save_span,
+                         cat="ckpt") as snap_span:
+            host = ser.snapshot_to_host(tree)
+            parts = ser.snapshot_parts(host)
+            snap_span.end(**parts)
+        for k, v in parts.items():
+            self.stats[k] += v
+        return host
 
     def wait(self) -> None:
         if self._pending is not None:

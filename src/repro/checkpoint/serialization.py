@@ -43,6 +43,7 @@ from pathlib import Path
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import zstandard
 
@@ -185,9 +186,11 @@ def decode_chunk(name: str, blob: bytes, codec: str) -> bytes:
 
 
 class HostArray:
-    """Synchronous device->host snapshot of a (possibly sharded) jax.Array.
-    Taken BEFORE the async writer runs, so buffer donation in the next
-    train step can't corrupt the checkpoint.
+    """Device->host snapshot of a (possibly sharded) jax.Array.  The
+    checkpoint manager takes it on its writer thread from a private
+    ``device_copy`` of the state, which no step donates; without room on
+    the device for that copy, it takes it from the state itself, on the
+    training thread, before the next step can donate the buffers.
 
     Replicated shards are deduplicated by index window BEFORE the
     device->host copy: a leaf replicated over N devices costs one transfer
@@ -223,6 +226,24 @@ class HostArray:
             self.copy_s += t2 - t1
             self.nbytes += data.nbytes
             self.shards.append((idx, data, int(sh.device.id)))
+
+
+@jax.jit
+def _copy_arrays(xs):
+    return [jnp.copy(x) for x in xs]
+
+
+def device_copy(tree):
+    """jax.Array leaves -> copies in fresh device buffers with the same
+    shardings (one program, nothing donated, so a step that donates the
+    originals leaves the copies whole); everything else -> np copy.  The
+    copy is dispatched, not waited for."""
+    leaves, treedef = jax.tree.flatten(tree)
+    copies = iter(_copy_arrays([x for x in leaves
+                                if isinstance(x, jax.Array)]))
+    return jax.tree.unflatten(treedef, [
+        next(copies) if isinstance(x, jax.Array) else np.asarray(x).copy()
+        for x in leaves])
 
 
 def snapshot_to_host(tree):

@@ -6,7 +6,9 @@ RUN -> (every ckpt_every steps) QUIESCE/DRAIN -> SNAPSHOT -> RESUME
              drain (or cache) the data-prefetch queue
   snapshot = TrainState pytree + pipeline cursor + rng; nothing else exists
              to save — the functional step makes the proxy boundary
-             structural (DESIGN.md §2)
+             structural (DESIGN.md §2).  A device copy, fetched and copied
+             to host by the writer while the next steps run; where the
+             device has no room for it, the host snapshot, synchronously
   restore  = newest valid checkpoint, auto-resumed, placed with the
              step's state shardings on the current mesh.
 

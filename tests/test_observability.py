@@ -269,6 +269,7 @@ COORD_STATS_KEYS = {
 CKPT_MANAGER_STATS_KEYS = {
     "saves", "drain_device_s", "drain_write_wait_s", "snapshot_s",
     "snapshot_transfer_s", "snapshot_copy_s", "snapshot_bytes", "write_s",
+    "snapshot_device_copies", "snapshot_host_fallbacks",
     "gc_removed", "hash_s",
     "compress_s", "io_s", "bytes_written", "bytes_referenced",
     "last_bytes_written", "last_bytes_referenced", "chunks_gc_removed",

@@ -172,7 +172,10 @@ def test_host_array_counts_one_copy_of_replicated_shards():
                      "snapshot_bytes": 2 * data.nbytes}
 
 
-def test_save_splits_snapshot_and_drain(tmp_path, enabled, monkeypatch):
+def test_save_splits_snapshot_and_drain(tmp_path, enabled, annotations,
+                                        monkeypatch):
+    """The drain's two parts on the training thread, then the device copy
+    there; the snapshot of that copy, with its parts, on the writer."""
     state = {"w": jnp.arange(64 * 64, dtype=jnp.float32).reshape(64, 64),
              "b": jnp.ones(7, jnp.bfloat16), "step": jnp.int32(3),
              "seed": np.int64(5)}
@@ -198,9 +201,9 @@ def test_save_splits_snapshot_and_drain(tmp_path, enabled, monkeypatch):
     real_wait()
     st = mgr.stats
     assert st["saves"] == 2
+    assert st["snapshot_device_copies"] == 2
+    assert st["snapshot_host_fallbacks"] == 0
     assert st["snapshot_transfer_s"] > 0 and st["snapshot_copy_s"] > 0
-    assert st["snapshot_transfer_s"] + st["snapshot_copy_s"] \
-        <= st["snapshot_s"]
     assert st["snapshot_bytes"] == 2 * state_bytes
     assert st["drain_device_s"] >= 2 * 0.03
     assert st["drain_write_wait_s"] >= 2 * 0.02
@@ -209,17 +212,34 @@ def test_save_splits_snapshot_and_drain(tmp_path, enabled, monkeypatch):
         spans.setdefault(e.name, []).append(e)
     assert "ckptmgr.drain" not in spans
     # the drain is its two parts: from the device drain's start to the
-    # snapshot's start, save by save
-    drained = sum(s.t0 - d.t0 for d, s in zip(spans["ckptmgr.drain_device"],
-                                              spans["ckptmgr.snapshot"]))
+    # device copy's start, save by save
+    drained = sum(c.t0 - d.t0 for d, c in zip(spans["ckptmgr.drain_device"],
+                                              spans["ckptmgr.device_copy"]))
     assert st["drain_device_s"] + st["drain_write_wait_s"] \
         == pytest.approx(drained, abs=5e-3)
     assert st["drain_device_s"] == pytest.approx(
         sum(e.dur for e in spans["ckptmgr.drain_device"]), abs=2e-3)
     assert st["drain_write_wait_s"] == pytest.approx(
         sum(e.dur for e in spans["ckptmgr.write_wait"]), abs=2e-3)
+    # the training thread's stall is the device copy
+    assert st["snapshot_s"] == pytest.approx(
+        sum(e.dur for e in spans["ckptmgr.device_copy"]), abs=2e-3)
+    saves = [e.span_id for e in spans["ckptmgr.save"]]
+    assert [e.parent_id for e in spans["ckptmgr.device_copy"]] == saves
+    assert [e.parent_id for e in spans["ckptmgr.snapshot"]] == saves
+    # the device copy on the training thread; each snapshot on its save's
+    # writer thread, before that write
+    threads = {}
+    for name, event, tid in annotations.log:
+        if event == "enter":
+            threads.setdefault(name, []).append(tid)
+    main = threading.get_ident()
+    assert threads["ckptmgr.device_copy"] == [main, main]
+    assert threads["ckptmgr.snapshot"] == threads["ckptmgr.write"]
+    assert main not in threads["ckptmgr.snapshot"]
     # each snapshot span carries its own save's parts
-    for e in spans["ckptmgr.snapshot"]:
+    for e, w in zip(spans["ckptmgr.snapshot"], spans["ckptmgr.write"]):
+        assert e.t0 + e.dur <= w.t0 + 1e-6
         assert e.args["snapshot_bytes"] == state_bytes
         assert e.args["snapshot_transfer_s"] + e.args["snapshot_copy_s"] \
             <= e.dur
