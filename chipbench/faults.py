@@ -10,7 +10,9 @@ runs imports this module.
                    first entry) is shifted by 1 where the update makes it;
   save_altered     one element of every save's host copy is shifted by 1
                    where the snapshot makes it (``serialization.
-                   snapshot_to_host``, which the checkpoint manager calls).
+                   snapshot_to_host``, which the checkpoint manager's
+                   writer thread calls on the save's device copy, or the
+                   training thread where the device lacks room for one).
 """
 from __future__ import annotations
 

@@ -7,7 +7,11 @@ Files, all found by the names in ``BENCHMARK.json``:
 
   configs/<config>.json        sizes (the system's own field names), the
                                source, what was reduced, and the name of
-                               the plain reference under reference/
+                               the plain reference under reference/;
+                               nested sections (``moe``, ``mla``,
+                               ``encoder``) by the field names of the
+                               program's config dataclasses; a list
+                               becomes a tuple where the field is one
   traffic/<traffic>.json       the parameters of that traffic mix
   workloads/<cell>.json        the cell's own values on top of the
                                traffic's (batch, nominal rate, limits)
@@ -32,10 +36,12 @@ One process, one cell, one run:
 """
 from __future__ import annotations
 
+import dataclasses
 import importlib.util
 import json
 import shutil
 import time
+import typing
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from pathlib import Path
@@ -195,7 +201,7 @@ class Run:
     cell: Cell
     peaks: dict
     steps: int = 0                      # train steps in the window
-    flops_per_step: float = 0.0
+    flops_per_step: Optional[float] = None   # None: flops.py cannot count it
     ckpt_stats: List[dict] = field(default_factory=list)
     program_spans: List[tuple] = field(default_factory=list)  # (name, t0, dur)
     compile: dict = field(default_factory=dict)
@@ -205,12 +211,51 @@ class Run:
     prog: dict = field(default_factory=dict)   # program outputs checked
 
 
+def _field_value(hint, value, where: str):
+    """A file's value for a field typed `hint`: a nested object built as
+    the config dataclass the field holds, a list made a tuple where the
+    field is a tuple, anything else as it is."""
+    if value is None:
+        return None
+    kinds = (typing.get_args(hint) if typing.get_origin(hint) is typing.Union
+             else (hint,))
+    for kind in kinds:
+        if dataclasses.is_dataclass(kind):
+            if not isinstance(value, dict):
+                raise BenchError(f"{where} must be an object of "
+                                 f"{kind.__name__}'s fields")
+            return _build(kind, value, where, strict=True)
+        if typing.get_origin(kind) is tuple and isinstance(value, list):
+            return tuple(value)
+    return value
+
+
+def _build(cls, values: dict, where: str, strict: bool):
+    """`cls` from a file's object, each field by its declared type.  A key
+    that is no field of `cls` is skipped at the top level (`source`,
+    `reduced`, ...) and refused inside a nested section (`strict`), where
+    it can only be a misspelt field."""
+    hints = typing.get_type_hints(cls)
+    names = [f.name for f in dataclasses.fields(cls) if f.init]
+    if strict:
+        unknown = sorted(set(values) - set(names))
+        if unknown:
+            raise BenchError(f"{where}: {cls.__name__} has no field "
+                             f"{unknown[0]!r}")
+    kw = {n: _field_value(hints[n], values[n], f"{where}.{n}")
+          for n in names if n in values}
+    try:
+        return cls(**kw)
+    except TypeError as e:          # a required field left out
+        raise BenchError(f"{where}: {e}") from None
+
+
 def program_config(cfg: dict):
-    """The system's ArchConfig from a configuration file's sizes."""
-    import dataclasses
+    """The system's ArchConfig from a configuration file: its fields by
+    their declared types, nested sections (``moe``, ``mla``, ...) as the
+    program's own config dataclasses."""
     from repro.configs.base import ArchConfig
-    names = {f.name for f in dataclasses.fields(ArchConfig)}
-    return ArchConfig(**{k: v for k, v in cfg.items() if k in names})
+    return _build(ArchConfig, cfg, cfg.get("name", "config"), strict=False)
 
 
 def _injected(fn, at: int):

@@ -151,8 +151,8 @@ def main(argv=None, require_tpu: bool = True, base=None,
              else {"bf16_flops_per_s": float("nan")})
     run = harness.Run(cell=cell, peaks=peaks)
     p = cell.params
-    run.flops_per_step = train_step_flops(
-        cell.cfg, p["global_batch"], p["seq_len"])["total"]
+    step_flops = train_step_flops(cell.cfg, p["global_batch"], p["seq_len"])
+    run.flops_per_step = step_flops["total"] if step_flops else None
 
     trace_dir = harness.SCRATCH / "trace"
     state = {}

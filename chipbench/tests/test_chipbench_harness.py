@@ -2,6 +2,7 @@
 configurations and per-layer readers found by name in a temp directory;
 the refusal to run without a TPU; and `correct` coming out false for the
 float8 control and for each fault planted in the timed path."""
+import dataclasses
 import json
 import os
 import subprocess
@@ -9,9 +10,11 @@ import sys
 
 import pytest
 
-from chipbench import compare, control, faults, harness
-from chipbench.tests.tiny import (LIMITS, NOSAVE_LIMITS, ROOT, TINY,
+from chipbench import compare, control, faults, flops, harness, run
+from chipbench.tests.tiny import (LIMITS, NOSAVE_LIMITS, ROOT, SEED, TINY,
                                   make_base, restore_jax_config, run_cell)
+from repro.configs import ARCHS, reduce_for_smoke
+from repro.configs.base import ArchConfig
 
 __all__ = ["restore_jax_config"]
 CELLS = ("tiny.train_save", "tiny.train_nosave")
@@ -74,6 +77,90 @@ def test_new_cell_found_by_name_with_no_code_edited(tmp_path, capsys,
     assert rc == 0 and res["correct"] is True
     assert res["metrics"] == {"window.steps": {"value": 3.0,
                                                "unit": "steps"}}
+
+
+class _AtComparison(Exception):
+    """Raised where the run would be compared with its reference."""
+
+
+def test_new_mla_moe_cell_found_by_name_with_no_code_edited(
+        tmp_path, capsys, restore_jax_config, monkeypatch):
+    """A configuration with latent attention and experts (DeepSeek-V2-Lite
+    through ``reduce_for_smoke``, written as JSON) and a cell that saves,
+    added as files: the harness finds the cell by name, builds the
+    program's config with its nested sections, counts its FLOPs, trains
+    with saves, reads the first save back through the system's reader and
+    compiles the step for its footprint.  It stops at the comparison,
+    which needs that model's plain reference: none is here yet."""
+    base = make_base(tmp_path, cells=())
+    cfg = dataclasses.asdict(reduce_for_smoke(ARCHS["deepseek-v2-lite-16b"]))
+    cfg.update(name="tiny-mla-moe", reduced=[])
+    (base / "configs" / "tiny-mla-moe.json").write_text(json.dumps(cfg))
+    cell = "tiny-mla-moe.train_save"
+    (base / "workloads" / f"{cell}.json").write_text(
+        json.dumps({"global_batch": 4, "seq_len": 32, "log_every": 1,
+                    "ckpt_every": 2, "check_steps": 2, "limits": LIMITS,
+                    "nominal_steps_per_s": 4.0}))
+    bench_file = tmp_path / "BENCHMARK.json"
+    bench = json.loads(bench_file.read_text())
+    bench["workloads"].append({"name": cell, "config": "tiny-mla-moe",
+                               "traffic": "train_save", "chips": 1,
+                               "why": "test"})
+    bench_file.write_text(json.dumps(bench))
+
+    def stop(r, seed):
+        raise _AtComparison(r)
+    monkeypatch.setattr(harness, "check", stop)
+    with pytest.raises(_AtComparison) as stopped:
+        run.main(["--workload", cell, "--seed", str(SEED), "--seconds", "1",
+                  "--trace", "0"], require_tpu=False, base=base,
+                 bench_file=bench_file)
+    r = stopped.value.args[0]
+    assert r.cell.name == cell and r.steps == 4
+    built = harness.program_config(r.cell.cfg)
+    assert built.moe.n_routed == 8 and built.mla.kv_lora_rank == 32
+    assert r.flops_per_step \
+        == flops.train_step_flops(cfg, 4, 32)["total"] > 0
+    assert r.prog["counters"] == (4, 4)
+    assert r.prog["roundtrip"] == (0, [])
+    assert set(r.prog["losses"]) == {0, 1}
+    # the first save holds the latent attention's and the experts' leaves
+    leaves = " ".join(r.prog["params"])
+    assert "w_uk" in leaves and "router" in leaves
+    memory = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+              if '"phase": "memory"' in ln]
+    assert memory and memory[0]["footprint"] > 0
+    assert not harness.SCRATCH.exists()
+
+
+@pytest.mark.parametrize("name", sorted(ARCHS))
+def test_program_config_round_trips_every_arch(name):
+    """Every configuration the program knows, written as a file holds it,
+    comes back equal, nested sections and tuples included, and hashable
+    (the program keys compiled steps by it)."""
+    cfg = harness.program_config(
+        json.loads(json.dumps(dataclasses.asdict(ARCHS[name]))))
+    assert cfg == ARCHS[name]
+    assert hash(cfg) == hash(ARCHS[name])
+
+
+def test_program_config_of_the_smollm_file_is_as_before():
+    """The configuration file as run: its fields passed as they are."""
+    cfg = json.loads((harness.HERE / "configs" / "smollm-135m-1l.json")
+                     .read_text())
+    names = {f.name for f in dataclasses.fields(ArchConfig)}
+    assert harness.program_config(cfg) \
+        == ArchConfig(**{k: v for k, v in cfg.items() if k in names})
+
+
+@pytest.mark.parametrize("section,key", [("moe", "topk"), ("mla", "q_lora")])
+def test_unknown_key_in_a_nested_section_is_refused(section, key):
+    """A misspelt field of a nested section is an error, not a default."""
+    cfg = json.loads(json.dumps(dataclasses.asdict(
+        ARCHS["deepseek-v2-lite-16b"])))
+    cfg[section][key] = 6
+    with pytest.raises(harness.BenchError, match=repr(key)):
+        harness.program_config(cfg)
 
 
 def test_unknown_workload_is_refused(base, capsys):

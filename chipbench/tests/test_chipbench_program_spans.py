@@ -96,18 +96,23 @@ def test_new_readers_read_nothing_from_a_program_without_them():
 
 def test_new_readers_read_a_tiny_train_with_saves(tmp_path, enabled):
     """On a real ``train()`` with saves every reader finds its counter or
-    span; the snapshot's two parts fit inside it.  Without a device trace
+    span; each save's fetch and host copy fit inside its own
+    ``ckptmgr.snapshot`` span (on the writer thread, apart from the
+    device copy that ``save.snapshot_s`` reads).  Without a device trace
     (``traces.reduce`` gives {} on the CPU) each leaves its metric out."""
     res = _tiny_train(tmp_path)
+    events = trace.recorder().snapshot()
     run = harness.Run(cell=None, peaks={}, trace={})
     run.ckpt_stats = [dict(res.ckpt_stats)]
-    run.program_spans = [(e.name, e.t0, e.dur)
-                         for e in trace.recorder().snapshot()
+    run.program_spans = [(e.name, e.t0, e.dur) for e in events
                          if getattr(e, "dur", None) is not None]
     for name in NEW_READERS:
         assert harness.load_reader(name)(run) is None, name
     run.trace = DEVICE_TRACE
     got = {n: harness.load_reader(n)(run) for n in NEW_READERS}
     assert all(v is not None and v > 0 for v in got.values()), got
-    assert got["save.transfer_s"] + got["save.host_copy_s"] \
-        <= harness.load_reader("save.snapshot_s")(run)
+    snaps = [e for e in events if e.name == "ckptmgr.snapshot"]
+    assert len(snaps) == res.ckpt_stats["saves"] == 2
+    for e in snaps:
+        assert e.dur >= e.args["snapshot_transfer_s"] \
+            + e.args["snapshot_copy_s"] > 0, e.args
